@@ -4,9 +4,13 @@ routing (full artifacts at the repo root, smoke artifacts under the
 gitignored ``benchmarks/_smoke/``)."""
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
 import time
 from pathlib import Path
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -50,6 +54,61 @@ def bench_out_path(name: str, smoke: bool,
         d.mkdir(exist_ok=True)
         return str(d / f"BENCH_{name}.json")
     return f"BENCH_{name}.json"
+
+
+# forced host devices behind a mesh column on CPU
+HOST_MESH_DEVICES = 8
+
+
+def mesh_devices() -> int:
+    """Devices a bench's mesh column spans: every visible accelerator
+    device, or ``HOST_MESH_DEVICES`` forced host devices on CPU."""
+    import jax
+    if jax.default_backend() == "cpu":
+        return HOST_MESH_DEVICES
+    return len(jax.devices())
+
+
+def mesh_column(module: str, smoke: bool, cells: Callable[[int], object]):
+    """A bench's mesh column: ``cells(devices)`` over ``mesh_devices()``.
+
+    It runs in this process whenever the process sees that many devices
+    (an accelerator host, or a CPU process started with the host-device
+    flag).  A plain CPU process cannot add devices after JAX is imported,
+    so there it runs ``python -m <module> --mesh-child`` with ``XLA_FLAGS``
+    forcing the host devices and parses the child's ``MESH_JSON`` line.
+    A single accelerator has no mesh: the column is ``None``."""
+    import jax
+    n = mesh_devices()
+    if n > 1 and len(jax.devices()) >= n:
+        return cells(n)
+    if jax.default_backend() != "cpu":
+        return None
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    cmd = [sys.executable, "-m", module, "--mesh-child"]
+    if smoke:
+        cmd.append("--smoke")
+    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                         cwd=str(Path(__file__).resolve().parents[1]),
+                         timeout=3600)
+    if out.returncode != 0:
+        raise RuntimeError(f"mesh child failed:\n{out.stderr[-4000:]}")
+    for line in out.stdout.splitlines():
+        if line.startswith("MESH_JSON:"):
+            return json.loads(line[len("MESH_JSON:"):])
+    raise RuntimeError(f"mesh child emitted no MESH_JSON line:\n"
+                       f"{out.stdout[-2000:]}")
+
+
+def run_mesh_child(cells: Callable[[int], object]) -> None:
+    """``--mesh-child`` body: print ``cells(HOST_MESH_DEVICES)`` on one
+    ``MESH_JSON`` line for ``mesh_column`` in the parent."""
+    import jax
+    assert len(jax.devices()) >= HOST_MESH_DEVICES, (
+        "run via the parent, which sets XLA_FLAGS="
+        f"--xla_force_host_platform_device_count={HOST_MESH_DEVICES}")
+    print("MESH_JSON:" + json.dumps(cells(HOST_MESH_DEVICES)))
 
 
 class Csv:

@@ -13,11 +13,11 @@ conflated with dispatch throughput) for K simulated clients:
   OP-group chunk (fl/fleet.BatchedEngine)
 
 Every (model, K) cell also grows a ``mesh`` row: the batched engine
-re-timed 1-device vs MESH_DEVICES forced-host-devices on a
-``make_flat_mesh((MESH_DEVICES, 1))`` data-axis mesh (the shard_map fleet
-step of ISSUE 10), with 1-dev-vs-mesh equivalence flags.  The mesh rows are
-produced by a ``--mesh-child`` subprocess because the host device count is
-fixed at jax import (same pattern as benchmarks/server_step.py).
+re-timed 1-device vs a ``make_flat_mesh((devices, 1))`` data-axis mesh (the
+shard_map fleet step), with 1-dev-vs-mesh equivalence flags.  The mesh
+spans every device of an accelerator host, in this process; on CPU it spans
+8 forced host devices in a ``--mesh-child`` subprocess, because the host
+device count is fixed at jax import (``benchmarks.common.mesh_column``).
 
     PYTHONPATH=src python -m benchmarks.fleet_scaling           # full sweep
     PYTHONPATH=src python -m benchmarks.fleet_scaling --smoke   # CI: K=4 vgg
@@ -40,8 +40,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
 import sys
 import time
 from typing import Dict, List, Optional
@@ -49,6 +47,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from benchmarks.common import mesh_column, mesh_devices, run_mesh_child
 from repro.configs.lm_small import LM16M
 from repro.configs.vgg import VGG5
 from repro.data.loader import FleetLoader
@@ -65,7 +64,6 @@ MODELS: Dict[str, dict] = {
 }
 KS = (4, 16, 64)
 ITERS = 2            # truncated local round: keeps the K=64 cells tractable
-MESH_DEVICES = 8     # the mesh rows' forced-host-device count (data axis)
 
 
 def _client_data(name: str, spec: dict, K: int) -> List[dict]:
@@ -115,25 +113,25 @@ def _bench_engine(engine_name: str, spec: dict, clients: List[dict], K: int,
 
 
 # -----------------------------------------------------------------------------
-# mesh column (runs in the --mesh-child subprocess: 8 forced host devices)
+# mesh column (benchmarks.common.mesh_column)
 # -----------------------------------------------------------------------------
 def mesh_cell(name: str, spec: dict, clients: List[dict], K: int,
-              rounds: int, iters: int) -> Dict:
+              rounds: int, iters: int, devices: int) -> Dict:
     """One (model, K) cell: batched engine 1-device vs the
-    ``(MESH_DEVICES, 1)`` data-axis mesh, plus equivalence flags from the
+    ``(devices, 1)`` data-axis mesh, plus equivalence flags from the
     round-0 averaged params of the two runs (bitwise is not promised at
     data > 1 — see docs/API.md — so ``allclose`` at fp32 tolerance is the
     gated flag)."""
     from repro.parallel.sharding import make_flat_mesh
     s1, p1 = _bench_engine("batched", spec, clients, K, rounds, iters,
                            return_params=True)
-    mesh = make_flat_mesh((MESH_DEVICES, 1))
+    mesh = make_flat_mesh((devices, 1))
     s8, p8 = _bench_engine("batched", spec, clients, K, rounds, iters,
                            mesh=mesh, return_params=True)
     a = [np.asarray(l) for l in jax.tree_util.tree_leaves(p1)]
     b = [np.asarray(l) for l in jax.tree_util.tree_leaves(p8)]
     return {
-        "model": name, "K": K, "devices": MESH_DEVICES,
+        "model": name, "K": K, "devices": devices,
         "s_per_round_1dev": round(s1, 4),
         "s_per_round_mesh": round(s8, 4),
         "speedup_mesh": round(s1 / s8, 3) if s8 else float("inf"),
@@ -143,18 +141,14 @@ def mesh_cell(name: str, spec: dict, clients: List[dict], K: int,
     }
 
 
-def run_mesh_child(smoke: bool) -> None:
-    """--mesh-child: emit the mesh rows for the same (model, K) grid as
-    ``run`` on one MESH_JSON line (parsed by the parent)."""
-    assert len(jax.devices()) >= MESH_DEVICES, (
-        "run via the parent, which sets XLA_FLAGS="
-        f"--xla_force_host_platform_device_count={MESH_DEVICES}")
+def _mesh_cells(smoke: bool, devices: int) -> List[Dict]:
+    """The mesh rows for the same (model, K) grid as ``run``."""
     cells = []
     for name, ks, rounds in _grid(smoke):
         spec = MODELS[name]
         for K in ks:
             clients = _client_data(name, spec, K)
-            cell = mesh_cell(name, spec, clients, K, rounds, ITERS)
+            cell = mesh_cell(name, spec, clients, K, rounds, ITERS, devices)
             cells.append(cell)
             print(f"mesh {name} K={K:<4d} "
                   f"1dev={cell['s_per_round_1dev']:8.2f}s "
@@ -162,28 +156,7 @@ def run_mesh_child(smoke: bool) -> None:
                   f"x{cell['speedup_mesh']} "
                   f"allclose={cell['mesh_allclose']}",
                   file=sys.stderr, flush=True)
-    print("MESH_JSON:" + json.dumps(cells))
-
-
-def _mesh_rows(smoke: bool) -> List[Dict]:
-    """Spawn the forced-8-device child and collect its mesh rows."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                        f"{MESH_DEVICES}")
-    cmd = [sys.executable, "-m", "benchmarks.fleet_scaling", "--mesh-child"]
-    if smoke:
-        cmd.append("--smoke")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         cwd=os.path.dirname(os.path.dirname(
-                             os.path.abspath(__file__))),
-                         timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(f"mesh child failed:\n{out.stderr[-4000:]}")
-    for line in out.stdout.splitlines():
-        if line.startswith("MESH_JSON:"):
-            return json.loads(line[len("MESH_JSON:"):])
-    raise RuntimeError(f"mesh child emitted no MESH_JSON line:\n"
-                       f"{out.stdout[-2000:]}")
+    return cells
 
 
 def _grid(smoke: bool):
@@ -218,11 +191,12 @@ def run(smoke: bool = False, out_path: Optional[str] = None) -> Dict:
                 results.append(cell)
                 print(f"{name} K={K:<4d} {eng:<10s} {s:8.2f} s/round",
                       flush=True)
-    mesh = _mesh_rows(smoke)
+    mesh = mesh_column("benchmarks.fleet_scaling", smoke,
+                       lambda n: _mesh_cells(smoke, n)) or []
     payload = {"backend": jax.default_backend(), "smoke": smoke,
-               "mesh_devices": MESH_DEVICES, "local_iters": ITERS,
+               "mesh_devices": mesh_devices(), "local_iters": ITERS,
                "results": results, "mesh": mesh}
-    if not smoke:
+    if not smoke and mesh:
         # the ISSUE 10 acceptance cell, recorded in the committed artifact
         # and gated by tools/check_bench.py: at least one K >= 64 mesh row
         # beats the 1-device batched engine
@@ -245,7 +219,7 @@ def bench_fleet_scaling():
     m = payload["mesh"][0] if payload["mesh"] else {}
     return 0.0, (f"{len(payload['results'])} engine cells; batched "
                  f"{batched[0]['s_per_round']:.2f} s/round @K="
-                 f"{batched[0]['K']}; mesh({MESH_DEVICES},1) "
+                 f"{batched[0]['K']}; mesh({payload['mesh_devices']},1) "
                  f"x{m.get('speedup_mesh')} vs 1-dev "
                  f"(allclose={m.get('mesh_allclose')})")
 
@@ -258,11 +232,11 @@ def main():
                     help="output JSON (default: BENCH_fleet_scaling.json, "
                          "or benchmarks/_smoke/ under --smoke)")
     ap.add_argument("--mesh-child", action="store_true",
-                    help="internal: emit the mesh rows "
-                         "(spawned by the parent with forced host devices)")
+                    help="internal: emit the mesh rows (spawned on CPU by "
+                         "the parent with forced host devices)")
     args = ap.parse_args()
     if args.mesh_child:
-        run_mesh_child(smoke=args.smoke)
+        run_mesh_child(lambda n: _mesh_cells(args.smoke, n))
     else:
         run(smoke=args.smoke, out_path=args.out)
 

@@ -21,6 +21,7 @@ from benchmarks.hetero import bench_hetero
 from benchmarks.hierarchy import bench_hierarchy
 from benchmarks.server_step import bench_server_step
 from benchmarks.serving import bench_serving
+from repro.compile_cache import enable_compile_cache
 
 
 def bench_roofline():
@@ -111,6 +112,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     selected = ([s.strip() for s in args.only.split(",") if s.strip()]
                 or list(BENCHES))
     print("name,us_per_call,derived")

@@ -8,12 +8,13 @@ issues O(K x leaves) jnp ops.  This bench measures steady-state
 aggregation wall-clock for K in {4, 16, 64, 256} over two scenarios
 (plain weighted averaging; top-k error feedback + int8 wire format) and
 emits machine-readable ``BENCH_server_step.json``.  Each cell also grows
-a ``mesh`` column: the same fused round timed on 1 vs 8 (forced host)
-devices via ``ShardedFlatLayout``/``ShardedServerStep`` over
-``make_flat_mesh((1, 8))``, with per-cell sharded-vs-reference
-equivalence flags (``sharded_bitwise`` / ``sharded_allclose``) — the
-column is produced by a ``--mesh-child`` subprocess because the host
-device count is fixed at jax import.
+a ``mesh`` column: the same fused round timed on 1 device vs a
+``make_flat_mesh((1, devices))`` mesh via ``ShardedFlatLayout`` /
+``ShardedServerStep``, with per-cell sharded-vs-reference equivalence
+flags (``sharded_bitwise`` / ``sharded_allclose``).  The mesh spans every
+device of an accelerator host, in this process; on CPU it spans 8 forced
+host devices in a ``--mesh-child`` subprocess, because the host device
+count is fixed at jax import (``benchmarks.common.mesh_column``).
 
     PYTHONPATH=src python -m benchmarks.server_step           # full sweep
     PYTHONPATH=src python -m benchmarks.server_step --smoke   # CI: K=4 only
@@ -30,8 +31,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
 import sys
 import time
 from typing import Dict, List
@@ -40,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.common import mesh_column, mesh_devices, run_mesh_child
 from repro.configs import get_smoke_config
 from repro.configs.vgg import VGG5
 from repro.fl.flatbuf import get_server_step, reference_server_step
@@ -53,11 +53,6 @@ SCENARIOS = {
     "avg": dict(density=1.0, quantize=False),
     "topk_int8": dict(density=0.01, quantize=True),
 }
-# the mesh column: every cell is re-timed 1-device vs MESH_DEVICES-device
-# (ShardedServerStep over make_flat_mesh((1, MESH_DEVICES))) in a child
-# process that forces that many host devices -- the device count is fixed
-# at jax import, so the parent cannot flip it per column.
-MESH_DEVICES = 8
 
 
 def _client_rows(program, params, K: int) -> List:
@@ -91,7 +86,7 @@ def bench_cell(program, params, K: int, density: float, quantize: bool,
     def fused_round():
         deltas = layout.rows_to_deltas(rows, g_flat)
         # gather the error rows like the real loops do — a fresh buffer per
-        # round, required because ServerStep donates them off-CPU
+        # round, required because ServerStep donates them
         err_rows = None if err is None else err[all_ids]
         new_g, new_err = step(g_flat, deltas, weights, err_rows)
         return layout.unflatten(new_g), new_err
@@ -124,14 +119,14 @@ def _bench_models(smoke: bool):
 
 
 def mesh_cell(program, params, K: int, density: float, quantize: bool,
-              reps: int) -> Dict:
-    """One (model, K, scenario) cell timed 1-device vs MESH_DEVICES-device,
+              reps: int, devices: int) -> Dict:
+    """One (model, K, scenario) cell timed 1-device vs ``devices``-device,
     with sharded-vs-reference equivalence flags.  Must run in a process
-    with >= MESH_DEVICES host devices (the --mesh-child mode)."""
+    that sees ``devices`` devices (``benchmarks.common.mesh_column``)."""
     from repro.parallel.sharding import make_flat_mesh
     base = program.flat_layout(params)
     lay = program.flat_layout(params,
-                              mesh=make_flat_mesh((1, MESH_DEVICES)))
+                              mesh=make_flat_mesh((1, devices)))
     rows = _client_rows(program, params, K)
     weights = list(np.arange(1, K + 1, dtype=np.float64))
     track = density < 1.0
@@ -141,10 +136,11 @@ def mesh_cell(program, params, K: int, density: float, quantize: bool,
     g8 = lay.flatten(params)
 
     def round_on(layout, g, step):
-        err = (jnp.zeros((K, layout.padded), jnp.float32) if track else None)
-
         def fn():
+            # fresh delta and error rows every round: the step donates both
             deltas = layout.rows_to_deltas(rows, g)
+            err = (jnp.zeros((K, layout.padded), jnp.float32) if track
+                   else None)
             return step(g, deltas, weights, err)
         return fn
 
@@ -155,7 +151,7 @@ def mesh_cell(program, params, K: int, density: float, quantize: bool,
     ref_g = np.asarray(one()[0])
     new_g = np.asarray(eight()[0])[:base.padded]
     return {
-        "devices": MESH_DEVICES,
+        "devices": devices,
         "fused_ms_1dev": round(ms1, 3),
         "fused_ms_8dev": round(ms8, 3),
         "speedup_8dev": round(ms1 / ms8, 2) if ms8 else float("inf"),
@@ -164,12 +160,8 @@ def mesh_cell(program, params, K: int, density: float, quantize: bool,
     }
 
 
-def run_mesh_child(smoke: bool) -> None:
-    """--mesh-child: emit the mesh column for the same cell grid as
-    ``run`` on one MESH_JSON line (parsed by the parent)."""
-    assert len(jax.devices()) >= MESH_DEVICES, (
-        "run via the parent, which sets XLA_FLAGS="
-        f"--xla_force_host_platform_device_count={MESH_DEVICES}")
+def _mesh_cells(smoke: bool, devices: int) -> Dict[str, Dict]:
+    """The mesh column for the same cell grid as ``run``."""
     ks = (4,) if smoke else KS
     reps = 1
     cells = {}
@@ -183,34 +175,16 @@ def run_mesh_child(smoke: bool) -> None:
             for scen, kw in SCENARIOS.items():
                 if smoke and scen != "avg":
                     continue
-                cell = mesh_cell(program, params, K, reps=reps, **kw)
+                cell = mesh_cell(program, params, K, reps=reps,
+                                 devices=devices, **kw)
                 cells[f"{name}|{K}|{scen}"] = cell
                 print(f"mesh {name} K={K:<4d} {scen:<10s} "
                       f"1dev={cell['fused_ms_1dev']:8.1f}ms "
-                      f"8dev={cell['fused_ms_8dev']:8.1f}ms "
+                      f"{devices}dev={cell['fused_ms_8dev']:8.1f}ms "
                       f"x{cell['speedup_8dev']} "
                       f"bitwise={cell['sharded_bitwise']}",
                       file=sys.stderr, flush=True)
-    print("MESH_JSON:" + json.dumps(cells))
-
-
-def _mesh_column(smoke: bool) -> Dict[str, Dict]:
-    """Spawn the forced-8-device child and collect its per-cell column."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                        f"{MESH_DEVICES}")
-    cmd = [sys.executable, "-m", "benchmarks.server_step", "--mesh-child"]
-    if smoke:
-        cmd.append("--smoke")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(f"mesh child failed:\n{out.stderr[-4000:]}")
-    for line in out.stdout.splitlines():
-        if line.startswith("MESH_JSON:"):
-            return json.loads(line[len("MESH_JSON:"):])
-    raise RuntimeError(f"mesh child emitted no MESH_JSON line:\n"
-                       f"{out.stdout[-2000:]}")
+    return cells
 
 
 def run(smoke: bool = False, out_path: str = None) -> Dict:
@@ -242,16 +216,17 @@ def run(smoke: bool = False, out_path: str = None) -> Dict:
                       f"ref={cell['ref_ms']:8.1f}ms "
                       f"fused={cell['fused_ms']:8.1f}ms "
                       f"x{cell['speedup']}", flush=True)
-    # mesh column: re-time every cell 1-device vs 8-device in a child that
-    # forces 8 host devices, and record sharded-vs-reference equivalence
-    mesh = _mesh_column(smoke)
+    # mesh column: re-time every cell 1-device vs the mesh, and record
+    # sharded-vs-reference equivalence
+    mesh = mesh_column("benchmarks.server_step", smoke,
+                       lambda n: _mesh_cells(smoke, n)) or {}
     for cell in results:
         if "skipped" in cell:
             continue
         cell["mesh"] = mesh.get(
             f"{cell['model']}|{cell['K']}|{cell['scenario']}")
     payload = {"backend": jax.default_backend(), "smoke": smoke,
-               "mesh_devices": MESH_DEVICES, "results": results}
+               "mesh_devices": mesh_devices(), "results": results}
     with open(out_path, "w") as f:
         json.dump(payload, f, indent=2)
     print(f"wrote {out_path}")
@@ -276,10 +251,10 @@ if __name__ == "__main__":
                     help="output JSON (default: BENCH_server_step.json, "
                          "or benchmarks/_smoke/ under --smoke)")
     ap.add_argument("--mesh-child", action="store_true",
-                    help="internal: emit the 8-device mesh column "
-                         "(spawned by the parent with forced host devices)")
+                    help="internal: emit the mesh column (spawned on CPU by "
+                         "the parent with forced host devices)")
     args = ap.parse_args()
     if args.mesh_child:
-        run_mesh_child(smoke=args.smoke)
+        run_mesh_child(lambda n: _mesh_cells(args.smoke, n))
     else:
         run(smoke=args.smoke, out_path=args.out)

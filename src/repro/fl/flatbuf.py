@@ -309,14 +309,14 @@ class ServerStep:
             meta = layout.block_meta(self.density)
             self._meta = meta
             self._kmax = int(meta[:, 1].max())
-        # donate the big (K, n) buffers (deltas, error rows) — they are
-        # consumed by the step; skipped on CPU where donation is a no-op
-        cpu = jax.default_backend() == "cpu"
-        self._step = jax.jit(self._step_impl,
-                             donate_argnums=() if cpu else (1, 3))
-        # reduce's signature drops the leading global: deltas/err shift left
-        self._reduce = jax.jit(self._reduce_core,
-                               donate_argnums=() if cpu else (0, 2))
+        # donate the (K, n) error rows on every backend: the new rows are
+        # written in their place, so a caller must not read the rows it
+        # passed in afterwards (the deltas have no output to alias)
+        self._step = jax.jit(self._step_impl, donate_argnums=(
+            (3,) if self.track_errors else ()))
+        # reduce's signature drops the leading global: err shifts left
+        self._reduce = jax.jit(self._reduce_core, donate_argnums=(
+            (2,) if self.track_errors else ()))
         self.reduce_calls = 0
 
     def _reduce_core(self, deltas: jnp.ndarray, w: jnp.ndarray,
@@ -477,7 +477,6 @@ class ShardedServerStep(ServerStep):
         key = (masked, reduce_only)
         if key in self._shmaps:
             return self._shmaps[key]
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels.topk_compress.ops import topk_compress_rows
         track, quant = self.track_errors, self.quantize
@@ -550,12 +549,15 @@ class ShardedServerStep(ServerStep):
                     P("model", None) if track else rep)
         n_out = 1 + int(reduce_only and masked) + int(track)
         out_specs = tuple([vec] * (n_out - int(track)) + [row] * int(track))
-        # check_rep=False: the quantize path runs a pallas_call inside the
-        # mapped body and shard_map's replication checker has no rule for
-        # it; the psum placement over "data" is explicit above.
-        fn = jax.jit(shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                               out_specs=out_specs if n_out > 1
-                               else out_specs[0], check_rep=False))
+        # check_vma=False: the quantize path runs a pallas_call inside the
+        # mapped body and shard_map's varying-axes checker has no rule for
+        # it; the psum placement over "data" is explicit above.  The EF
+        # rows are donated as in ServerStep (a padded copy when the client
+        # axis pads to the data size, the caller's rows otherwise).
+        fn = jax.jit(jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                                   out_specs=out_specs if n_out > 1
+                                   else out_specs[0], check_vma=False),
+                     donate_argnums=(3,) if track else ())
         self._shmaps[key] = fn
         return fn
 

@@ -152,7 +152,7 @@ def make_sharded_fleet_step(program: SplitProgram, quantize: bool, mesh):
     an explicit ``shard_map`` that splits the stacked client axis along the
     mesh ``data`` axis — each device trains ``G / data`` clients with the
     plain per-device program, and because clients are independent the body
-    needs ZERO collectives (``check_rep=False``: outputs are client-sharded
+    needs ZERO collectives (``check_vma=False``: outputs are client-sharded
     by construction).
 
     Explicit ``shard_map``, not GSPMD propagation, on purpose: letting the
@@ -173,7 +173,6 @@ def make_sharded_fleet_step(program: SplitProgram, quantize: bool, mesh):
     arrive with the client axis a multiple of the ``data`` size
     (``parallel.sharding.client_chunk_pad``) and placed by
     ``SplitProgram.shard_batches``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     @partial(jax.jit, static_argnames=("op",))
@@ -186,9 +185,9 @@ def make_sharded_fleet_step(program: SplitProgram, quantize: bool, mesh):
 
             return jax.vmap(one_client, in_axes=(None, 0))(params, batches)
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P(), P("data"), P()),
-                         out_specs=P("data"), check_rep=False)(
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(), P("data"), P()),
+                             out_specs=P("data"), check_vma=False)(
                              params, batches, lr)
 
     return fleet_step
@@ -199,7 +198,6 @@ def make_sharded_fleet_step_masked(program: SplitProgram, quantize: bool,
     """Width-masked (HeteroFL) variant of ``make_sharded_fleet_step``: the
     group-wide mask rides along replicated like the params — every shard
     applies the same subnetwork mask to its slice of the client axis."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     @partial(jax.jit, static_argnames=("op",))
@@ -218,9 +216,9 @@ def make_sharded_fleet_step_masked(program: SplitProgram, quantize: bool,
             p0 = jax.tree_util.tree_map(jnp.multiply, mask, params)
             return jax.vmap(one_client, in_axes=(None, 0))(p0, batches)
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P(), P(), P("data"), P()),
-                         out_specs=P("data"), check_rep=False)(
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(), P(), P("data"), P()),
+                             out_specs=P("data"), check_vma=False)(
                              params, mask, batches, lr)
 
     return fleet_step

@@ -1,47 +1,26 @@
-"""Version- and backend-compat shims for the Pallas TPU API.
+"""Backend dispatch for the Pallas TPU kernels.
 
-Two concerns live here:
-
-* ``CompilerParams``: jax renamed ``pltpu.CompilerParams`` to
-  ``pltpu.TPUCompilerParams`` (and newer releases are renaming it back);
-  kernels import ``CompilerParams`` from here so both spellings of the
-  installed jax work unchanged.
-
-* ``default_interpret`` / ``resolve_interpret``: whether a Pallas kernel
-  should run compiled or through the interpreter is a property of the
-  *backend*, not of the call site.  Every kernel wrapper in
-  ``repro.kernels.*.ops`` takes ``interpret=None`` and resolves it here:
-  compiled on TPU (the lowering these kernels are written against),
-  interpret/reference mode everywhere else — on CPU there is nothing to
-  compile *to*, and on GPU the Triton lowering silently drops the TPU
-  compiler params and has never been validated for these kernel bodies,
-  so it stays opt-in (``REPRO_PALLAS_INTERPRET=0``) until someone
-  validates it.  Tests and benchmarks can still force either mode with an
-  explicit ``interpret=True/False`` argument; the
-  ``REPRO_PALLAS_INTERPRET`` environment variable (``0``/``1``) overrides
-  the backend default process-wide (read at trace time).
+Whether a Pallas kernel runs compiled or through the interpreter is a
+property of the *backend*, not of the call site.  Every kernel wrapper in
+``repro.kernels.*.ops`` takes ``interpret=None`` and resolves it here:
+compiled on TPU (the lowering these kernels are written against),
+interpret/reference mode on every other backend.  Tests can still force
+either mode with an explicit ``interpret=True/False`` argument; nothing
+else can, so a run on the chip always executes the compiled kernels.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-if hasattr(pltpu, "TPUCompilerParams"):
-    CompilerParams = pltpu.TPUCompilerParams
-else:
-    CompilerParams = pltpu.CompilerParams
+CompilerParams = pltpu.CompilerParams
 
 
 def default_interpret() -> bool:
     """True when Pallas kernels should run in interpreter/reference mode:
-    every backend except TPU (module docstring), unless
-    ``REPRO_PALLAS_INTERPRET`` forces a mode."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env not in ("", "auto"):
-        return env not in ("0", "false", "False")
+    every backend except TPU."""
     return jax.default_backend() != "tpu"
 
 

@@ -32,7 +32,7 @@ def quantize(x: jnp.ndarray, interpret: Optional[bool] = None
     q, s = quantize_pallas(flat, block_rows=min(br, flat.shape[0]),
                            interpret=resolve_interpret(interpret))
     return (q[:R].reshape(shape),
-            s[:R].reshape(shape[:-1]))
+            s[:R, 0].reshape(shape[:-1]))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -40,13 +40,13 @@ def dequantize(q: jnp.ndarray, scales: jnp.ndarray,
                interpret: Optional[bool] = None) -> jnp.ndarray:
     shape = q.shape
     flat = q.reshape(-1, shape[-1])
-    sflat = scales.reshape(-1)
+    sflat = scales.reshape(-1, 1)
     R = flat.shape[0]
     br = 256
     pad = (-R) % min(br, R) if R else 0
     if pad:
         flat = jnp.pad(flat, ((0, pad), (0, 0)))
-        sflat = jnp.pad(sflat, (0, pad))
+        sflat = jnp.pad(sflat, ((0, pad), (0, 0)))
     out = dequantize_pallas(flat, sflat, block_rows=min(br, flat.shape[0]),
                             interpret=resolve_interpret(interpret))
     return out[:R].reshape(shape)

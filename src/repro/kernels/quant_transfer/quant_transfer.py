@@ -20,17 +20,21 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     scale = jnp.maximum(absmax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(x / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale[:, 0]
+    s_ref[...] = scale                                  # (BR, 1)
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)
-    o_ref[...] = (q * s_ref[...][:, None]).astype(o_ref.dtype)
+    o_ref[...] = (q * s_ref[...]).astype(o_ref.dtype)
 
 
 def quantize_pallas(x: jnp.ndarray, block_rows: int = 256,
                     interpret: bool = False):
-    """x (rows, cols) -> (int8 (rows, cols), fp32 scales (rows,))."""
+    """x (rows, cols) -> (int8 (rows, cols), fp32 scales (rows, 1)).
+
+    The scales are a 2-D column, not a vector: a 1-D ``(br,)`` block gets
+    Mosaic's 256-element tiling while XLA tiles a longer vector by 512 or
+    1024, and the compiled kernel then fails its layout check."""
     R, C = x.shape
     br = min(block_rows, R)
     assert R % br == 0, "pad rows upstream"
@@ -40,9 +44,9 @@ def quantize_pallas(x: jnp.ndarray, block_rows: int = 256,
         grid=grid,
         in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((br, C), lambda i: (i, 0)),
-                   pl.BlockSpec((br,), lambda i: (i,))],
+                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int8),
-                   jax.ShapeDtypeStruct((R,), jnp.float32)],
+                   jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         compiler_params=CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
@@ -52,6 +56,8 @@ def quantize_pallas(x: jnp.ndarray, block_rows: int = 256,
 def dequantize_pallas(q: jnp.ndarray, scales: jnp.ndarray,
                       out_dtype=jnp.float32, block_rows: int = 256,
                       interpret: bool = False) -> jnp.ndarray:
+    """int8 ``(rows, cols)`` and fp32 ``(rows, 1)`` scales -> ``out_dtype``
+    ``(rows, cols)``."""
     R, C = q.shape
     br = min(block_rows, R)
     assert R % br == 0
@@ -59,7 +65,7 @@ def dequantize_pallas(q: jnp.ndarray, scales: jnp.ndarray,
         _dequant_kernel,
         grid=(R // br,),
         in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0)),
-                  pl.BlockSpec((br,), lambda i: (i,))],
+                  pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
         compiler_params=CompilerParams(

@@ -19,6 +19,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.lm_small import SMALL_CONFIGS
 from repro.data.synthetic import make_token_stream
 from repro.models import api
@@ -33,6 +34,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = SMALL_CONFIGS[args.arch]
     params = api.init(cfg, jax.random.PRNGKey(args.seed))

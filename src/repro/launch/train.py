@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs.lm_small import SMALL_CONFIGS
 from repro.core import costmodel as cm
 from repro.core.agent import PPOAgent, PPOConfig
@@ -82,6 +83,7 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = resolve_arch(args.arch)
     program = get_split_program(cfg)

@@ -384,7 +384,6 @@ def _moe_block_sharded(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     outputs psum over 'model'.  Decode steps (S == 1) use a lossless
     capacity C = T_local * k, so serving never drops tokens.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     assert cfg.moe is not None
@@ -525,9 +524,9 @@ def _moe_block_sharded(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         out = lax.psum(out, tp_ax)
         return out.reshape(Bl, Sl, d)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=x_spec, check_rep=False,
+        out_specs=x_spec, check_vma=False,
     )(*operands)
 
 

@@ -134,7 +134,9 @@ def test_masked_server_step_matches_reference(density, quantize):
     err = (jnp.zeros((K, layout.padded), jnp.float32)
            if density < 1 else None)
     step = get_server_step(layout, density, quantize)
-    g2, e2 = step(g, deltas, w, err, masks=masks)
+    # the step donates its error rows: hand it a copy
+    g2, e2 = step(g, deltas, w, None if err is None else jnp.copy(err),
+                  masks=masks)
     p_ref, e_ref = reference_server_step(
         layout, params, [layout.unflatten(deltas[i]) for i in range(K)],
         w, err, density=density, quantize=quantize, masks=masks)

@@ -159,7 +159,10 @@ def test_single_edge_bitwise_equals_flat_step():
                if density < 1.0 else None)
         stacked = jnp.stack([layout.flatten(d) for d in deltas])
         g_flat = layout.flatten(g)
-        ref_g, ref_err = step(g_flat, stacked, w, err)
+        # the step donates its error rows: hand it a copy, the hierarchy
+        # below consumes the originals
+        ref_g, ref_err = step(g_flat, stacked, w,
+                              None if err is None else jnp.copy(err))
         hg, herr, used = hierarchical_apply(step, root, g_flat, stacked, w,
                                             err, num_edges=1)
         assert used == 1

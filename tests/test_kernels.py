@@ -126,6 +126,28 @@ def test_topk_kernel_matches_jnp_path_masked_blocks():
         np.asarray(topk_compress(t, 3, 128)))
 
 
+@pytest.mark.parametrize("nb,block,kmax", [(13, 128, 7), (8, 256, 5),
+                                           (3, 128, 9), (21, 128, 12)])
+def test_topk_kernel_row_tiles_match_blocks_ref(nb, block, kmax):
+    """The kernel selects within 8 blocks per grid step (a ragged last step
+    when 8 does not divide the block count); per-block (valid, k) budgets,
+    masked blocks and heavy ties must still select exactly the entries
+    ``_topk_blocks_ref`` selects."""
+    from repro.kernels.topk_compress.ops import _topk_blocks_ref
+    from repro.kernels.topk_compress.topk_compress import topk_compress_pallas
+    rng = np.random.RandomState(nb)
+    x = rng.randint(-4, 5, size=(nb, block)).astype(np.float32)
+    x[::3] = rng.randn(len(x[::3]), block)
+    valid = rng.randint(1, block + 1, size=nb)
+    valid[nb // 2] = 0                       # a fully masked block
+    k = np.clip(rng.randint(1, kmax + 1, size=nb), 1, np.maximum(valid, 1))
+    meta = jnp.asarray(np.stack([valid, k], 1).astype(np.int32))
+    out = topk_compress_pallas(jnp.asarray(x.reshape(-1)), meta, kmax, block,
+                               interpret=True)
+    ref = _topk_blocks_ref(jnp.asarray(x), meta, kmax).reshape(-1)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
 def test_topk_density_from_true_size():
     """The density-skew fix: k comes from the true (unpadded) element count,
     so leaves smaller than a block and padded tails keep ~density * n
