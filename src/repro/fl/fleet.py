@@ -49,6 +49,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data.loader import FleetLoader
 from repro.models.split_program import SplitProgram
@@ -253,11 +254,16 @@ class SequentialEngine:
             else:
                 p_k = params
             for it in range(self.local_iters):
-                batch = loader.next_batch(k)
-                if self.augment and "images" in batch:
-                    batch["images"] = flip_augment(batch["images"], self.seed,
-                                                   round_idx, k, it)
-                jbatch = {key: jnp.asarray(v) for key, v in batch.items()}
+                # one client-iteration's host data, then its device put
+                with TraceAnnotation("fl.stack", op=int(ops[k]), clients=1):
+                    batch = loader.next_batch(k)
+                    if self.augment and "images" in batch:
+                        batch["images"] = flip_augment(
+                            batch["images"], self.seed, round_idx, k, it)
+                    with TraceAnnotation("fl.put", bytes=sum(
+                            v.nbytes for v in batch.values())):
+                        jbatch = {key: jnp.asarray(v)
+                                  for key, v in batch.items()}
                 if hetero is not None:
                     p_k, _ = self._step_masked(p_k, mask, jbatch,
                                                jnp.float32(lr), int(ops[k]))
@@ -373,11 +379,13 @@ class BatchedEngine:
                         [imgs, np.repeat(imgs[:1], C - len(ks), axis=0)])
                 nb["images"] = imgs
             per_iter.append(nb)
-        batches = {key: jnp.asarray(np.stack([pb[key] for pb in per_iter],
-                                             axis=1))
-                   for key in per_iter[0]}
-        if self.mesh is not None:
-            batches = self.program.shard_batches(batches, self.mesh)
+        host = {key: np.stack([pb[key] for pb in per_iter], axis=1)
+                for key in per_iter[0]}
+        with TraceAnnotation("fl.put",
+                             bytes=sum(v.nbytes for v in host.values())):
+            batches = {key: jnp.asarray(v) for key, v in host.items()}
+            if self.mesh is not None:
+                batches = self.program.shard_batches(batches, self.mesh)
         return batches
 
     def run_round(self, params: Params, loader: FleetLoader,
@@ -403,8 +411,11 @@ class BatchedEngine:
                 else:
                     pad_to = len(ks) + client_chunk_pad(len(ks),
                                                         self.data_size)
-                batches = self._stack_round(loader, ks, round_idx,
-                                            pad_to=pad_to)
+                # the chunk's host data and its device put (profiler spans
+                # fl.stack and fl.put, docs/ARCHITECTURE.md)
+                with TraceAnnotation("fl.stack", op=op, clients=len(ks)):
+                    batches = self._stack_round(loader, ks, round_idx,
+                                                pad_to=pad_to)
                 if hetero is not None:
                     finals, _ = self._step_masked(
                         params, hetero.mask_tree(ks[0]), batches,

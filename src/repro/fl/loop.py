@@ -62,6 +62,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import CheckpointManager
 from repro.core.controller import FedAdaptController
@@ -469,105 +470,121 @@ def run_federated(
     eval_fn = jax.jit(lambda p, b: program.eval_metric(p, b))
     test_batch = {k: jnp.asarray(v) for k, v in test_data.items()}
 
+    # Each round runs as sibling profiler spans (TraceAnnotation: about a
+    # microsecond when no trace is active; docs/ARCHITECTURE.md, "Observing
+    # a round"): fl.plan, fl.train, fl.account, fl.aggregate, fl.sync and,
+    # when one is due, fl.checkpoint.  The engines add fl.stack and fl.put
+    # inside fl.train.
     for r in range(start_round, fl.rounds):
         lr = fl.lr * (fl.lr_drop_factor if r >= fl.lr_drop_round else 1.0)
         # --- plan offloading for this round --------------------------------
-        bandwidths = sim.bandwidths(r) if sim is not None else None
-        ops = plan.plan(r, times, bandwidths)
+        with TraceAnnotation("fl.plan", round=r):
+            bandwidths = sim.bandwidths(r) if sim is not None else None
+            ops = plan.plan(r, times, bandwidths)
+            alive = injector.round_mask(K, round_idx=r)
+            if cohort is not None:
+                # only this round's seeded cohort participates; everyone
+                # else counts as dropped for this round's accounting
+                alive &= cohort.member_mask(r)
+                if virtualized:
+                    # stage the live cohort's EF rows on the store's worker
+                    # thread — the host-side gather overlaps the cohort's
+                    # local training, and the post-training fetch
+                    # (survivors are a subset of the live cohort) consumes
+                    # the staged rows
+                    delta_errors.prefetch(np.flatnonzero(alive))
         # --- local training (fleet engine) ----------------------------------
-        alive = injector.round_mask(K, round_idx=r)
-        if cohort is not None:
-            # only this round's seeded cohort participates; everyone else
-            # counts as dropped for this round's accounting
-            alive &= cohort.member_mask(r)
-            if virtualized:
-                # stage the live cohort's EF rows on the store's worker
-                # thread — the host-side gather overlaps the cohort's local
-                # training, and the post-training fetch (survivors are a
-                # subset of the live cohort) consumes the staged rows
-                delta_errors.prefetch(np.flatnonzero(alive))
-        idxs, rows = engine.run_round(params, loaders, ops,
-                                      [int(k) for k in np.flatnonzero(alive)],
-                                      r, lr, hetero=hetero)
+        with TraceAnnotation("fl.train", round=r):
+            idxs, rows = engine.run_round(
+                params, loaders, ops,
+                [int(k) for k in np.flatnonzero(alive)], r, lr,
+                hetero=hetero)
         # --- timing + straggler handling ------------------------------------
-        times, comm = clock.times(ops, r)
-        keep = np.ones(K, bool)
-        if fl.deadline_factor > 0:
-            keep = deadline_mask(times, fl.deadline_factor)
-        keep &= alive
-        weights = reweight(sizes, keep)
-        kept_pos = [i for i, k in enumerate(idxs) if keep[k]]
-        surv_idx = [idxs[i] for i in kept_pos]
-        surv_w = [weights[k] for k in surv_idx]
-        edges_used = 0
-        if kept_pos:
-            mask_rows = hetero.rows(surv_idx) if hetero is not None else None
-            if fused:
-                # fused flat-buffer server step: stack survivor deltas,
-                # top-k error feedback, optional int8, weighted apply — all
-                # one compiled dispatch (plus one stack, one unflatten);
-                # with num_edges > 0 the same pipeline runs tiered
-                # (fl/hierarchy.py: per-edge reduce, root apply)
-                deltas = layout.rows_to_deltas(take_rows(rows, kept_pos),
-                                               g_flat)
-                ids = jnp.asarray(np.asarray(surv_idx, np.int32))
-                if not track_errors:
-                    err_rows = None
-                elif virtualized:
-                    err_rows = delta_errors.fetch(surv_idx)
-                else:
-                    err_rows = delta_errors[ids]
-                if fl.num_edges > 0:
-                    g_flat, new_err, edges_used = hierarchical_apply(
-                        step, root, g_flat, deltas, surv_w, err_rows,
-                        mask_rows, num_edges=fl.num_edges)
-                else:
-                    g_flat, new_err = step(g_flat, deltas, surv_w, err_rows,
-                                           masks=mask_rows)
-                if track_errors:
-                    if virtualized:
-                        delta_errors.store(surv_idx, new_err)
+        with TraceAnnotation("fl.account", round=r):
+            times, comm = clock.times(ops, r)
+            keep = np.ones(K, bool)
+            if fl.deadline_factor > 0:
+                keep = deadline_mask(times, fl.deadline_factor)
+            keep &= alive
+            weights = reweight(sizes, keep)
+            kept_pos = [i for i, k in enumerate(idxs) if keep[k]]
+            surv_idx = [idxs[i] for i in kept_pos]
+            surv_w = [weights[k] for k in surv_idx]
+        # --- server step ----------------------------------------------------
+        with TraceAnnotation("fl.aggregate", round=r, clients=len(surv_idx)):
+            edges_used = 0
+            if kept_pos:
+                mask_rows = (hetero.rows(surv_idx) if hetero is not None
+                             else None)
+                if fused:
+                    # fused flat-buffer server step: stack survivor
+                    # deltas, top-k error feedback, optional int8, weighted
+                    # apply — all one compiled dispatch (plus one stack, one
+                    # unflatten); with num_edges > 0 the same pipeline runs
+                    # tiered (fl/hierarchy.py: per-edge reduce, root apply)
+                    deltas = layout.rows_to_deltas(
+                        take_rows(rows, kept_pos), g_flat)
+                    ids = jnp.asarray(np.asarray(surv_idx, np.int32))
+                    if not track_errors:
+                        err_rows = None
+                    elif virtualized:
+                        err_rows = delta_errors.fetch(surv_idx)
                     else:
-                        delta_errors = delta_errors.at[ids].set(new_err)
-                params = layout.unflatten(g_flat)
-                if not layout.exact_fp32:
-                    # narrower param dtypes round on unflatten: re-derive
-                    # the flat master from the rounded params so checkpoints
-                    # (which store params) stay a complete description of
-                    # the run state; for fp32 this would be a bitwise no-op
-                    g_flat = layout.flatten(params)
-            elif hetero is None and not track_errors and \
-                    not fl.quantize_deltas and \
-                    isinstance(rows, StackedRows):
-                # reference path, plain averaging, batched engine: keep the
-                # pre-fused stacked tensordot (one op per leaf) rather than
-                # degrading to a K-wide per-client loop
-                survivors = take_rows(rows, kept_pos)
-                params = fedavg_delta_stacked(params, survivors.tree,
-                                              surv_w)
-            else:
-                # reference per-leaf path (O(K x leaves) dispatches): the
-                # equivalence baseline for tests and benchmarks
-                ids = jnp.asarray(np.asarray(surv_idx, np.int32))
-                if not track_errors:
-                    err_rows = None
-                elif virtualized:
-                    err_rows = delta_errors.fetch(surv_idx)
-                else:
-                    err_rows = delta_errors[ids]
-                params, new_err = reference_server_step(
-                    layout, params, _delta_trees(
-                        params, rows_as_list(rows, kept_pos)),
-                    surv_w, err_rows, density=fl.delta_density,
-                    quantize=fl.quantize_deltas, masks=mask_rows)
-                if track_errors:
-                    if virtualized:
-                        delta_errors.store(surv_idx, new_err)
+                        err_rows = delta_errors[ids]
+                    if fl.num_edges > 0:
+                        g_flat, new_err, edges_used = hierarchical_apply(
+                            step, root, g_flat, deltas, surv_w, err_rows,
+                            mask_rows, num_edges=fl.num_edges)
                     else:
-                        delta_errors = delta_errors.at[ids].set(new_err)
+                        g_flat, new_err = step(g_flat, deltas, surv_w,
+                                               err_rows, masks=mask_rows)
+                    if track_errors:
+                        if virtualized:
+                            delta_errors.store(surv_idx, new_err)
+                        else:
+                            delta_errors = delta_errors.at[ids].set(new_err)
+                    params = layout.unflatten(g_flat)
+                    if not layout.exact_fp32:
+                        # narrower param dtypes round on unflatten:
+                        # re-derive the flat master from the rounded params
+                        # so checkpoints (which store params) stay a
+                        # complete description of the run state; for fp32
+                        # this would be a bitwise no-op
+                        g_flat = layout.flatten(params)
+                elif hetero is None and not track_errors and \
+                        not fl.quantize_deltas and \
+                        isinstance(rows, StackedRows):
+                    # reference path, plain averaging, batched engine:
+                    # keep the pre-fused stacked tensordot (one op per leaf)
+                    # rather than degrading to a K-wide per-client loop
+                    survivors = take_rows(rows, kept_pos)
+                    params = fedavg_delta_stacked(params, survivors.tree,
+                                                  surv_w)
+                else:
+                    # reference per-leaf path (O(K x leaves) dispatches):
+                    # the equivalence baseline for tests and benchmarks
+                    ids = jnp.asarray(np.asarray(surv_idx, np.int32))
+                    if not track_errors:
+                        err_rows = None
+                    elif virtualized:
+                        err_rows = delta_errors.fetch(surv_idx)
+                    else:
+                        err_rows = delta_errors[ids]
+                    params, new_err = reference_server_step(
+                        layout, params, _delta_trees(
+                            params, rows_as_list(rows, kept_pos)),
+                        surv_w, err_rows, density=fl.delta_density,
+                        quantize=fl.quantize_deltas, masks=mask_rows)
+                    if track_errors:
+                        if virtualized:
+                            delta_errors.store(surv_idx, new_err)
+                        else:
+                            delta_errors = delta_errors.at[ids].set(new_err)
         plan.feedback(times)
         # --- evaluation + checkpoint ----------------------------------------
-        acc = float(eval_fn(params, test_batch))
+        # the host waits here for the round's device work
+        with TraceAnnotation("fl.sync", round=r):
+            acc = float(eval_fn(params, test_batch))
         hist["accuracy"].append(acc)
         if keep.any():
             wall = float(np.max(times[keep]))
@@ -593,7 +610,8 @@ def run_federated(
         hist["dropped"].append(int(K - keep.sum()))
         if mgr is not None and fl.checkpoint_every and \
                 (r + 1) % fl.checkpoint_every == 0:
-            mgr.save(base_state_tree(params, delta_errors, ctl, K), r + 1)
+            with TraceAnnotation("fl.checkpoint", round=r):
+                mgr.save(base_state_tree(params, delta_errors, ctl, K), r + 1)
 
     hist_np = {k: np.asarray(v) for k, v in hist.items()}
     hist_np["params"] = params
