@@ -15,6 +15,8 @@ Three pieces:
   the sequential engine would draw — grouping clients differently across
   rounds never changes what any single client sees, and ``state/restore``
   keeps the bitwise-resume guarantee at fleet granularity.
+  ``next_indices(k)`` makes the same draw as rows of client ``k``'s
+  dataset, for an engine that keeps the data on the device.
 * ``dirichlet_partition`` — seeded Dirichlet(α) label-skew split of one
   dataset into K client shards (the standard non-IID benchmark protocol;
   see e.g. Hsu et al. and the heterogeneity survey arXiv:2307.09182).
@@ -140,13 +142,19 @@ class ClientLoader:
         self.epoch, self.cursor = state
         self._perm = self._permutation(self.epoch)
 
-    def next_batch(self) -> Dict[str, np.ndarray]:
+    def next_indices(self) -> np.ndarray:
+        """Rows of ``data`` in the next batch; advances ``(epoch, cursor)``
+        exactly as ``next_batch`` does, which draws through it."""
         if self.cursor + self.batch_size > self.n:
             self.epoch += 1
             self.cursor = 0
             self._perm = self._permutation(self.epoch)
         idx = self._perm[self.cursor:self.cursor + self.batch_size]
         self.cursor += self.batch_size
+        return idx
+
+    def next_batch(self) -> Dict[str, np.ndarray]:
+        idx = self.next_indices()
         return {k: v[idx] for k, v in self.data.items()}
 
     def skip(self, n: int):
@@ -254,9 +262,22 @@ class FleetLoader:
     def __len__(self) -> int:
         return self._K
 
+    @property
+    def datasets(self) -> List[Dict[str, np.ndarray]]:
+        """Every client's dataset in client order, without building any
+        stream (the batched engine's device-resident slab reads them)."""
+        if self._data is not None:
+            return list(self._data)
+        return [self._loaders[k].data for k in range(self._K)]
+
     def next_batch(self, k: int) -> Dict[str, np.ndarray]:
         """Client ``k``'s next batch (the sequential engine's draw)."""
         return self._get(k).next_batch()
+
+    def next_indices(self, k: int) -> np.ndarray:
+        """Rows of client ``k``'s dataset in its next batch: the same draw,
+        and the same stream advance, as ``next_batch(k)``."""
+        return self._get(k).next_indices()
 
     def next_batches(self, k_indices: Sequence[int],
                      pad_to: Optional[int] = None) -> Dict[str, np.ndarray]:

@@ -197,8 +197,11 @@ def run_federated_async(
             "only bitwise for fp32)")
     loaders = FleetLoader.for_clients(clients_data, fl.batch_size,
                                       seed=fl.seed)
+    # a cohort fleet's data stays on the host: only every-round training
+    # makes the whole fleet each round's working set (fl/fleet.py)
     engine = get_engine(fl.engine, program, fl.local_iters, fl.seed,
-                        fl.augment, fl.quantize_transfer, mesh=mesh)
+                        fl.augment, fl.quantize_transfer, mesh=mesh,
+                        resident=fl.cohort_size == 0)
     native_op = program.native_op
     seq = (clients_data[0]["tokens"].shape[1]
            if "tokens" in clients_data[0] else None)

@@ -16,10 +16,12 @@ interchangeable engines implement it (``FLConfig.engine``):
   round instead of K x local_iters, one compile per (config, OP, chunk
   size).  Per-client batch streams, shuffling and the
   horizontal-flip augmentation RNG are bitwise identical to the sequential
-  engine (batches are materialized host-side via
-  ``data.loader.FleetLoader.next_batches`` and stacked ``(G, I, B, ...)``),
-  so the same seed yields the same history up to float32 summation order
-  (drilled in tests/test_fleet.py).
+  engine (batches are stacked ``(G, I, B, ...)``: gathered on the device
+  from a resident ``FleetSlab`` of the fleet's data when every client
+  trains every round, else stacked host-side from
+  ``data.loader.FleetLoader.next_batches``), so the same seed yields the
+  same history up to float32 summation order (drilled in
+  tests/test_fleet.py).
 
 With ``FLConfig.mesh_shape`` set, the batched engine goes *mesh-parallel*
 (``make_sharded_fleet_step``): each chunk's client axis splits along the
@@ -57,15 +59,78 @@ from repro.models.split_program import SplitProgram
 Params = Any
 
 
-def flip_augment(images: np.ndarray, seed: int, round_idx: int, client: int,
-                 it: int) -> np.ndarray:
-    """Horizontal flip with p=0.5 (paper §V-B), keyed by
-    ``(seed, round, client, iter)`` so any engine — and any resumed run —
-    reproduces the exact augmentation stream."""
+def flip_mask(seed: int, round_idx: int, client: int, it: int,
+              n: int) -> np.ndarray:
+    """Which of a batch's ``n`` images flip horizontally, each with p=0.5
+    (paper §V-B), keyed by ``(seed, round, client, iter)`` so any engine —
+    and any resumed run — reproduces the exact augmentation stream."""
     rng = np.random.RandomState(
         (seed * 1_000_003 + round_idx * 1009 + client * 31 + it) % (2 ** 31))
-    flip = rng.rand(len(images)) < 0.5
+    return rng.rand(n) < 0.5
+
+
+def flip_augment(images: np.ndarray, seed: int, round_idx: int, client: int,
+                 it: int) -> np.ndarray:
+    """A ``(B, H, W, C)`` batch with ``flip_mask``'s images mirrored."""
+    flip = flip_mask(seed, round_idx, client, it, len(images))
     return np.where(flip[:, None, None, None], images[:, :, ::-1, :], images)
+
+
+@dataclasses.dataclass
+class FleetSlab:
+    """The whole fleet's client data on one device: per key, every client's
+    rows concatenated in client order, and each client's first row.  A
+    client's batch is then a gather of global rows, whatever the client
+    sizes (``dirichlet_partition`` shards included).  Rows of more than one
+    axis are stored flat, ``(N, prod(shape))``: a TPU tiles the two minor
+    axes, and a ``(N, 32, 32, 3)`` slab would be laid out anew, padded, for
+    every gather; ``shapes`` restores each key's row shape."""
+
+    arrays: Dict[str, jax.Array]
+    shapes: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    offsets: np.ndarray                     # (K,) first global row
+
+    @classmethod
+    def build(cls, datasets: Sequence[Dict[str, np.ndarray]],
+              device) -> Optional["FleetSlab"]:
+        """Upload ``datasets`` to ``device`` (None: the default device), or
+        return None when they would take over a quarter of the device's
+        memory (``memory_stats()["bytes_limit"]``; a backend that reports
+        no limit, as the CPU does, always takes the slab)."""
+        keys = list(datasets[0])
+        nbytes = sum(d[key].nbytes for d in datasets for key in keys)
+        stats = (device or jax.devices()[0]).memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if limit and nbytes > limit // 4:
+            return None
+        sizes = [len(d[keys[0]]) for d in datasets]
+        offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(
+            np.int64)
+        shapes = tuple((key, datasets[0][key].shape[1:]) for key in keys)
+        arrays = {}
+        for key, shape in shapes:
+            rows = np.concatenate([d[key] for d in datasets])
+            if len(shape) > 1:
+                rows = rows.reshape(len(rows), -1)
+            arrays[key] = jax.device_put(rows, device)
+        return cls(arrays, shapes, offsets)
+
+
+@partial(jax.jit, static_argnames=("shapes",))
+def _fleet_gather(slab: Dict[str, jax.Array], idx: jax.Array, flip,
+                  shapes: Tuple[Tuple[str, Tuple[int, ...]], ...]):
+    """A chunk's stacked batches from the device slab (``FleetSlab``
+    ``arrays`` and ``shapes``): ``idx`` ``(C, I, B)`` global rows, ``flip``
+    ``(C, I, B)`` bool (or None) mirrors the width axis of ``images``.  Pure
+    data movement, so the result is bitwise the host path's stack of
+    ``next_batches`` draws and ``flip_augment``."""
+    out = {key: slab[key].at[idx].get(mode="promise_in_bounds").reshape(
+        idx.shape + shape) for key, shape in shapes}
+    if flip is not None:
+        x = out["images"]                  # (C, I, B, H, W, ch)
+        out["images"] = jnp.where(flip[..., None, None, None],
+                                  x[..., ::-1, :], x)
+    return out
 
 
 def _sgd_update(program: SplitProgram, quantize: bool, params, batch, lr, op):
@@ -231,10 +296,12 @@ class SequentialEngine:
     name = "sequential"
 
     def __init__(self, program: SplitProgram, local_iters: int, seed: int,
-                 augment: bool, quantize: bool, mesh=None):
-        # ``mesh`` is accepted for engine-interface uniformity and ignored:
-        # the sequential oracle always runs the legacy per-client dispatches
-        # (with FLConfig.mesh_shape set it still benefits from the sharded
+                 augment: bool, quantize: bool, mesh=None,
+                 resident: bool = True):
+        # ``mesh`` and ``resident`` are accepted for engine-interface
+        # uniformity and ignored: the sequential oracle always runs the
+        # legacy per-client dispatches from host batches (with
+        # FLConfig.mesh_shape set it still benefits from the sharded
         # *server* step; only the batched engine shards local training)
         self.local_iters = local_iters
         self.seed = seed
@@ -312,19 +379,32 @@ class BatchedEngine:
     step anyway (``ShardedFlatLayout.rows_to_deltas``) — same
     compute-sharded / glue-pinned compromise PR 9 pinned for the layout.
     ``mesh=None`` is the exact legacy single-device engine, bitwise
-    (tests/test_mesh_fleet.py)."""
+    (tests/test_mesh_fleet.py).
+
+    ``resident`` (the loops pass ``FLConfig.cohort_size == 0``: every
+    client trains every round, so the fleet's data is each round's working
+    set) keeps that data on the device: the first draw from a loader
+    uploads it as a ``FleetSlab`` (on the mesh's home device, if any), and
+    each chunk puts only its rows and flip masks and gathers its batches
+    there (``_fleet_gather``).  A cohort fleet, or a slab over a quarter of
+    the device's memory, stacks on the host instead; both paths draw the
+    same ``next_indices`` and ``flip_mask`` streams and build the same
+    bytes (tests/test_fleet.py)."""
 
     name = "batched"
 
     def __init__(self, program: SplitProgram, local_iters: int, seed: int,
                  augment: bool, quantize: bool, max_group: int = 8,
-                 mesh=None):
+                 mesh=None, resident: bool = True):
         self.program = program
         self.local_iters = local_iters
         self.seed = seed
         self.augment = augment
         self.max_group = max(1, int(max_group))
         self.mesh = mesh
+        self.resident = resident
+        self._slab_loader: Optional[FleetLoader] = None
+        self._slab: Optional[FleetSlab] = None
         if mesh is not None:
             if "data" not in mesh.shape:
                 raise ValueError(f"mesh axes {tuple(mesh.shape)} must "
@@ -339,6 +419,7 @@ class BatchedEngine:
                 program, quantize, mesh)
             self._home = mesh.devices.flat[0]
         else:
+            self._home = None
             self.data_size = 1
             self.chunk = self.max_group
             self._step = make_fleet_step(program, quantize)
@@ -355,17 +436,63 @@ class BatchedEngine:
             groups.setdefault((int(ops[k]), width), []).append(k)
         return groups
 
+    def _slab_of(self, loader: FleetLoader) -> Optional[FleetSlab]:
+        """The device slab of ``loader``'s fleet, built on its first draw;
+        None where the host path stays (see the class docstring)."""
+        if self._slab_loader is not loader:
+            self._slab_loader = loader
+            self._slab = (FleetSlab.build(loader.datasets, self._home)
+                          if self.resident else None)
+        return self._slab
+
     def _stack_round(self, loader: FleetLoader, ks: List[int],
                      round_idx: int, pad_to: Optional[int] = None
                      ) -> Dict[str, jnp.ndarray]:
-        """Materialize the group's whole round of data host-side: for each
-        local iteration draw every client's next batch (the same per-client
-        streams the sequential engine consumes), augment, and stack to
-        ``(G, I, B, ...)``.  ``pad_to > len(ks)`` repeats the first client's
-        (augmented) rows up to that chunk size — stable compiled shapes and
-        shard-divisible client axes — without advancing any stream; on a
-        mesh the stack lands shard-wise placed (clients along ``data``)."""
+        """The group's whole round of data, ``(G, I, B, ...)``: for each
+        local iteration every client's next batch (the same per-client
+        streams the sequential engine consumes), augmented.  ``pad_to >
+        len(ks)`` repeats the first client's (augmented) rows up to that
+        chunk size — stable compiled shapes and shard-divisible client
+        axes — without advancing any stream; on a mesh the stack lands
+        shard-wise placed (clients along ``data``)."""
         C = max(len(ks), int(pad_to or 0))
+        slab = self._slab_of(loader)
+        if slab is not None:
+            return self._gather_round(slab, loader, ks, round_idx, C)
+        return self._host_round(loader, ks, round_idx, C)
+
+    def _gather_round(self, slab: FleetSlab, loader: FleetLoader,
+                      ks: List[int], round_idx: int, C: int
+                      ) -> Dict[str, jnp.ndarray]:
+        """Resident path: the host draws only the global rows ``(C, I,
+        B)`` and the flip masks, puts them, and the device gathers."""
+        idx = np.stack([np.stack([slab.offsets[k] + loader.next_indices(k)
+                                  for k in ks])
+                        for _ in range(self.local_iters)], axis=1)
+        idx = idx.astype(np.int32)
+        flip = None
+        if self.augment and "images" in slab.arrays:
+            flip = np.stack([np.stack([flip_mask(self.seed, round_idx, k,
+                                                 it, idx.shape[2])
+                                       for it in range(self.local_iters)])
+                             for k in ks])
+        if C > len(ks):        # padding rows repeat client 0's rows, flips
+            idx = np.concatenate([idx, np.repeat(idx[:1], C - len(ks), 0)])
+            if flip is not None:
+                flip = np.concatenate(
+                    [flip, np.repeat(flip[:1], C - len(ks), 0)])
+        with TraceAnnotation("fl.put", bytes=idx.nbytes + (
+                flip.nbytes if flip is not None else 0)):
+            idx, flip = jax.device_put((idx, flip), self._home)
+        batches = _fleet_gather(slab.arrays, idx, flip, slab.shapes)
+        if self.mesh is not None:
+            batches = self.program.shard_batches(batches, self.mesh)
+        return batches
+
+    def _host_round(self, loader: FleetLoader, ks: List[int],
+                    round_idx: int, C: int) -> Dict[str, jnp.ndarray]:
+        """Host path: draw, flip and stack the batches in numpy, then put
+        the stack."""
         per_iter: List[Dict[str, np.ndarray]] = []
         for it in range(self.local_iters):
             nb = loader.next_batches(ks, pad_to=C)           # (C, B, ...)
@@ -446,18 +573,21 @@ ENGINES = {"sequential": SequentialEngine, "batched": BatchedEngine}
 
 
 def get_engine(name: str, program: SplitProgram, local_iters: int, seed: int,
-               augment: bool, quantize: bool, mesh=None):
+               augment: bool, quantize: bool, mesh=None,
+               resident: bool = True):
     """Build the configured fleet engine.  ``mesh`` (from
     ``FLConfig.mesh_shape`` via the loops' ``_resolve_mesh``) turns the
-    batched engine mesh-parallel; the sequential engine accepts and ignores
-    it (it stays the single-device oracle the mesh path is tested
-    against)."""
+    batched engine mesh-parallel, and ``resident`` (the loops pass
+    ``cohort_size == 0``) lets it keep the fleet's data on the device; the
+    sequential engine accepts and ignores both (it stays the single-device
+    oracle the batched paths are tested against)."""
     try:
         cls = ENGINES[name]
     except KeyError:
         raise ValueError(f"unknown fleet engine {name!r}; "
                          f"known: {sorted(ENGINES)}") from None
-    return cls(program, local_iters, seed, augment, quantize, mesh=mesh)
+    return cls(program, local_iters, seed, augment, quantize, mesh=mesh,
+               resident=resident)
 
 
 # -----------------------------------------------------------------------------

@@ -240,3 +240,124 @@ def test_batched_engine_multiple_op_groups():
     np.testing.assert_array_equal(seq["ops"], bat["ops"])
     assert set(np.asarray(seq["ops"][0])) == {2, 4}
     assert _max_leaf_diff(seq["params"], bat["params"]) < 1e-4
+
+
+# =============================================================================
+# device-resident fleet data: the gather path builds the host path's bytes
+# =============================================================================
+def _vgg_fleet():
+    return split_clients(make_cifar_like(100, seed=3, hw=8), 5)
+
+
+def _lm_fleet():
+    return split_clients(token_dataset(40, 16, LM16M.vocab_size, seed=4), 5)
+
+
+def _dirichlet_fleet():
+    from repro.data.loader import dirichlet_partition
+    return dirichlet_partition(make_cifar_like(150, seed=5, hw=8), 5,
+                               alpha=0.5, seed=1, min_per_client=8)
+
+
+# (fleet, batch, augment, chunks as (clients, pad_to)): VGG with flips, a
+# padded chunk and two OP groups; LM tokens; Dirichlet shards of unequal size
+RESIDENT_CASES = {
+    "vgg-flips-pad-two-groups": (_vgg_fleet, 6, True,
+                                 [([0, 2, 4], 4), ([1, 3], None)]),
+    "lm-tokens": (_lm_fleet, 3, False, [([0, 1, 2, 3, 4], None)]),
+    "dirichlet-sizes": (_dirichlet_fleet, 4, True,
+                        [([3, 0], 3), ([4, 1, 2], None)]),
+}
+
+
+def _both_paths(case, rounds=3, iters=2):
+    """Each round's chunk batches and the streams' states after it, from a
+    resident engine and from a host-path engine on twin loaders."""
+    from repro.fl.fleet import BatchedEngine
+    fleet, batch, augment, chunks = RESIDENT_CASES[case]
+    clients = fleet()
+    prog = get_split_program(LM16M if "tokens" in clients[0] else VGG5)
+    out = []
+    for resident in (True, False):
+        eng = BatchedEngine(prog, iters, 11, augment, False,
+                            resident=resident)
+        loader = FleetLoader.for_clients(clients, batch, seed=2)
+        got = []
+        for r in range(rounds):            # crosses epoch boundaries
+            for ks, pad_to in chunks:
+                b = eng._stack_round(loader, ks, r, pad_to=pad_to)
+                got.append(({k: np.asarray(v) for k, v in b.items()},
+                            loader.state()))
+        assert (eng._slab is not None) == resident
+        out.append(got)
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
+def test_resident_gather_is_bitwise_the_host_stack(case):
+    resident, host = _both_paths(case)
+    for (a, _), (b, _) in zip(resident, host):
+        assert set(a) == set(b)
+        for key in a:
+            assert a[key].dtype == b[key].dtype
+            np.testing.assert_array_equal(a[key], b[key])
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
+def test_resident_gather_advances_streams_as_the_host_path(case):
+    resident, host = _both_paths(case)
+    assert [s for _, s in resident] == [s for _, s in host]
+
+
+@pytest.mark.parametrize("cohort_size", [0, 3])
+@pytest.mark.parametrize("loop", ["sync", "async"])
+def test_only_a_full_fleet_builds_a_device_slab(monkeypatch, loop,
+                                                cohort_size):
+    """Every client every round: the fleet's data goes to the device once
+    and each chunk gathers there; a sampled cohort stacks on the host."""
+    from repro.configs.vgg import VGGConfig
+    from repro.fl import fleet as fleet_mod
+    from repro.fl.async_loop import run_federated_async
+
+    calls = {"build": 0, "gather": 0}
+    build, gather = fleet_mod.FleetSlab.build, fleet_mod._fleet_gather
+
+    def counted_build(*a, **kw):
+        calls["build"] += 1
+        return build(*a, **kw)
+
+    def counted_gather(*a, **kw):
+        calls["gather"] += 1
+        return gather(*a, **kw)
+
+    monkeypatch.setattr(fleet_mod.FleetSlab, "build", counted_build)
+    monkeypatch.setattr(fleet_mod, "_fleet_gather", counted_gather)
+    tiny = VGGConfig(name="vgg-tiny", layers=("C4", "MP", "FC10"), ops=(2,),
+                     input_hw=8)
+    clients = split_clients(make_cifar_like(60, seed=0, hw=8), 6)
+    test = make_cifar_like(10, seed=9, hw=8)
+    fl = FLConfig(rounds=2, local_iters=1, batch_size=5, engine="batched",
+                  cohort_size=cohort_size)
+    run = run_federated if loop == "sync" else run_federated_async
+    run(tiny, clients, test, fl)
+    if cohort_size:
+        assert calls == {"build": 0, "gather": 0}
+    else:
+        assert calls["build"] == 1 and calls["gather"] > 0
+
+
+@pytest.mark.parametrize("seed,round_idx,client,it",
+                         [(0, 0, 0, 0), (7, 3, 5, 1), (2 ** 40 + 3, 9, 63, 4)])
+def test_flip_mask_is_flip_augments_mask(seed, round_idx, client, it):
+    from repro.fl.fleet import flip_augment, flip_mask
+    images = np.arange(6 * 2 * 3 * 1, dtype=np.float32).reshape(6, 2, 3, 1)
+    # the augmentation stream's seeding, written out as the reference
+    want = np.random.RandomState(
+        (seed * 1_000_003 + round_idx * 1009 + client * 31 + it)
+        % (2 ** 31)).rand(len(images)) < 0.5
+    mask = flip_mask(seed, round_idx, client, it, len(images))
+    np.testing.assert_array_equal(mask, want)
+    got = flip_augment(images, seed, round_idx, client, it)
+    flipped = [np.array_equal(g, x[:, ::-1]) for g, x in zip(got, images)]
+    kept = [np.array_equal(g, x) for g, x in zip(got, images)]
+    assert flipped == list(mask) and kept == list(~mask)

@@ -2,7 +2,8 @@
 each round emits its phases once, in order and without overlap; the
 engines' host-data spans nest inside ``fl.train`` (one per chunk, or one per
 client-iteration) with their device puts inside them, and the puts' byte
-counts are the stacked arrays'; and tracing changes no result."""
+counts are what the engine puts (the batched engine's rows and flips, the
+sequential engine's batches); and tracing changes no result."""
 import glob
 import os
 
@@ -103,7 +104,11 @@ def test_put_spans_nest_in_stack_spans_with_the_stacked_bytes(runs):
     stacks = [s for s in spans if s[0] == "fl.stack"]
     puts = [s for s in spans if s[0] == "fl.put"]
     assert len(puts) == len(stacks)
-    sample = 8 * 8 * 3 * 4 + 4               # float32 image, int32 label
+    if engine == "batched":
+        # the fleet's data is on the device: an int32 row and a flip flag
+        sample = 4 + 1
+    else:
+        sample = 8 * 8 * 3 * 4 + 4           # float32 image, int32 label
     for stack, put in zip(stacks, puts):
         assert _inside(put, stack)
         draws = stack[3]["clients"] * (ITERS if engine == "batched" else 1)
