@@ -217,6 +217,8 @@ def run(cell, seed: int, seconds: float, trace_dir: Optional[str],
     numbers = compare(cell.ref, cell.spec, inputs["test"], planner.captured,
                       refs)
     numbers["reference_s"] = time.perf_counter() - t_ref
+    print(f"chipbench: reference took {numbers['reference_s']:.3f} s",
+          file=sys.stderr, flush=True)
     window = planner.t1 - planner.t0
     K = cell.mix["clients"]
     return {"setup_s": planner.t0 - t_start,
@@ -232,7 +234,7 @@ def run(cell, seed: int, seconds: float, trace_dir: Optional[str],
 def default_reference(cell, inputs) -> List:
     return fedref.run(cell.ref, cell.spec, cell.mix, inputs["clients"],
                       inputs["ops"], inputs["fl_seed"],
-                      rounds=WARM_ROUNDS)
+                      rounds=WARM_ROUNDS).weights
 
 
 def control_readings(cell, seed: int) -> dict:
@@ -244,9 +246,9 @@ def control_readings(cell, seed: int) -> dict:
     args = (cell.ref, cell.spec, cell.mix, inputs["clients"], inputs["ops"],
             inputs["fl_seed"])
     bf16 = fedref.run(*args, rounds=WARM_ROUNDS, dtype=jnp.bfloat16,
-                      precision="default")
+                      precision="default").weights
     half = fedref.run(*args, rounds=WARM_ROUNDS, batch_fn=lambda b: {
-        k: v[:len(v) // 2] for k, v in b.items()})
+        k: v[:len(v) // 2] for k, v in b.items()}).weights
     return {name: compare(cell.ref, cell.spec, inputs["test"],
                           dict(enumerate(ws)), refs)
             for name, ws in (("bf16", bf16), ("half_batch", half))}
